@@ -29,6 +29,7 @@ from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import trace as _trace
 from ..opt.gia import solve_param_opt_batched
 from ..opt.structure import structure_signature
 from .plan import Plan
@@ -160,17 +161,24 @@ def sweep_scenarios(scenarios: Sequence, names: Optional[Sequence[str]] = None,
     rules / systems) are fine — that's what the grouping is for.
     """
     scenarios = list(scenarios)
+    with _trace.span("sweep", scenarios=len(scenarios), backend=backend):
+        return _sweep(scenarios, names, backend, tol, max_iter, parallel)
+
+
+def _sweep(scenarios: list, names, backend: str, tol: float, max_iter: int,
+           parallel: bool) -> SweepReport:
     if names is not None:
         names = list(names)
         if len(names) != len(scenarios):
             raise ValueError(f"{len(names)} names for {len(scenarios)} "
                              f"scenarios")
     t_start = time.time()
-    ms = [s.objective for s in scenarios]
-    probs = [s.problem() for s in scenarios]
-    groups: Dict[tuple, List[int]] = {}
-    for i, p in enumerate(probs):
-        groups.setdefault(structure_signature(p), []).append(i)
+    with _trace.span("sweep.build"):
+        ms = [s.objective for s in scenarios]
+        probs = [s.problem() for s in scenarios]
+        groups: Dict[tuple, List[int]] = {}
+        for i, p in enumerate(probs):
+            groups.setdefault(structure_signature(p), []).append(i)
 
     def solve_group(idxs: List[int]):
         return solve_param_opt_batched([probs[i] for i in idxs], tol=tol,
@@ -191,19 +199,20 @@ def sweep_scenarios(scenarios: Sequence, names: Optional[Sequence[str]] = None,
                 results[i] = r
 
     rows, plans = [], []
-    for i, (scn, m, r) in enumerate(zip(scenarios, ms, results)):
-        plan = scn._plan_from_result(m, r)
-        name = (names[i] if names is not None
-                else f"{scn.family_key}-{m.value}")
-        rows.append({
-            "name": name, "family": scn.family_key, "m": m.value,
-            "gamma": plan.gamma, "T_max": scn.T_max, "C_max": scn.C_max,
-            "K0": plan.K0, "Kn": plan.Kn, "B": plan.B,
-            "N": plan.N, "S": plan.cohort_S, "sampling": plan.sampling,
-            "E": plan.predicted_E, "T": plan.predicted_T,
-            "C": plan.predicted_C, "feasible": plan.feasible,
-            "converged": plan.converged, "iterations": r.iterations,
-        })
-        plans.append(plan)
+    with _trace.span("sweep.freeze"):
+        for i, (scn, m, r) in enumerate(zip(scenarios, ms, results)):
+            plan = scn._plan_from_result(m, r)
+            name = (names[i] if names is not None
+                    else f"{scn.family_key}-{m.value}")
+            rows.append({
+                "name": name, "family": scn.family_key, "m": m.value,
+                "gamma": plan.gamma, "T_max": scn.T_max, "C_max": scn.C_max,
+                "K0": plan.K0, "Kn": plan.Kn, "B": plan.B,
+                "N": plan.N, "S": plan.cohort_S, "sampling": plan.sampling,
+                "E": plan.predicted_E, "T": plan.predicted_T,
+                "C": plan.predicted_C, "feasible": plan.feasible,
+                "converged": plan.converged, "iterations": r.iterations,
+            })
+            plans.append(plan)
     return SweepReport(rows=tuple(rows), plans=tuple(plans), backend=backend,
                        n_groups=len(groups), wall_time_s=time.time() - t_start)

@@ -249,7 +249,9 @@ def make_round_fn(api, cfg: ArchConfig, fed: FedConfig, mesh: Mesh,
     batch: leaves (fl, K_max, B_local, ...), sharded P('fl', None, 'fsdp', ...).
     The round's first stage, ``genqsgd_round.local_steps(x_hat, batch,
     gamma)``, is exposed so a caller can see where the workers' replicas
-    live.
+    live.  The round's phases run under the ``jax.named_scope``s
+    ``local_steps``, ``worker_encode``, ``aggregate`` and ``server_update``,
+    which a profiler trace shows in each operation's name stack.
     """
     Kn = jnp.asarray(fed.Kn, jnp.int32)
 
@@ -552,69 +554,75 @@ def make_round_fn(api, cfg: ArchConfig, fed: FedConfig, mesh: Mesh,
         keys = jax.random.split(key, fed.n_workers + 1)
         wkeys, skey = keys[:-1], keys[-1]
 
-        params_w, losses = local_steps(x_hat, batch, gamma)
+        with jax.named_scope("local_steps"):
+            params_w, losses = local_steps(x_hat, batch, gamma)
 
-        # (5): normalized per-worker deltas, quantized per tensor
-        deltas = jax.tree.map(
-            lambda pw, xh: (pw - xh[None]) / gamma, params_w, x_hat)
-        s_dummy = (jnp.zeros(fed.n_workers) if sn_arr is None else sn_arr)
-        levels_fl, norms_fl = jax.vmap(worker_quantize)(deltas, wkeys,
-                                                        s_dummy)
+        with jax.named_scope("worker_encode"):
+            # (5): normalized per-worker deltas, quantized per tensor
+            deltas = jax.tree.map(
+                lambda pw, xh: (pw - xh[None]) / gamma, params_w, x_hat)
+            s_dummy = (jnp.zeros(fed.n_workers) if sn_arr is None else sn_arr)
+            levels_fl, norms_fl = jax.vmap(worker_quantize)(deltas, wkeys,
+                                                            s_dummy)
 
-        elias_bits = None
-        if fed.wire == "f32":
-            delta_hat = agg_f32(levels_fl, norms_fl, u)
-        elif fed.wire == "elias":
-            # exact workers (s=None) ride raw f32, exactly as priced
-            if not fed.sn_exact:
-                levels_fl, elias_bits = _elias_roundtrip(levels_fl)
-            delta_hat = agg_f32(levels_fl, norms_fl, u)
-        elif bucket is None:
-            body = {"int8": _agg_int8_local, "int4": _agg_int4_local,
-                    "rs_ag": _agg_rs_ag_local}[fed.wire]
-            delta_hat = make_agg_sm(x_hat, body)(levels_fl, norms_fl)
-        elif fed.wire in ("int8", "int4"):
-            # bucketed level wires: compact payload moves inside shard_map,
-            # dequantize outside on logical-global arrays (no further
-            # fl-axis traffic — the gathered levels are fl-replicated).
-            # Unlike the per-tensor paths, cross-wire agreement here is
-            # ulp-level, not bitwise: the decode sits in a different fusion
-            # context, so XLA's FMA choices can flip a few stochastic
-            # roundings upstream.
-            g = make_gather_sm(x_hat, fed.wire == "int4")(levels_fl)
-            delta_hat = jax.tree.map(lambda d: combine_fl(d, u),
-                                     _decode_fl(g, norms_fl))
-        else:  # bucketed rs_ag: decode per worker, then rs+ag the f32 mean
-            delta_hat = make_mean_sm(x_hat)(_decode_fl(levels_fl, norms_fl))
+        with jax.named_scope("aggregate"):
+            elias_bits = None
+            if fed.wire == "f32":
+                delta_hat = agg_f32(levels_fl, norms_fl, u)
+            elif fed.wire == "elias":
+                # exact workers (s=None) ride raw f32, exactly as priced
+                if not fed.sn_exact:
+                    levels_fl, elias_bits = _elias_roundtrip(levels_fl)
+                delta_hat = agg_f32(levels_fl, norms_fl, u)
+            elif bucket is None:
+                body = {"int8": _agg_int8_local, "int4": _agg_int4_local,
+                        "rs_ag": _agg_rs_ag_local}[fed.wire]
+                delta_hat = make_agg_sm(x_hat, body)(levels_fl, norms_fl)
+            elif fed.wire in ("int8", "int4"):
+                # bucketed level wires: compact payload moves inside shard_map,
+                # dequantize outside on logical-global arrays (no further
+                # fl-axis traffic — the gathered levels are fl-replicated).
+                # Unlike the per-tensor paths, cross-wire agreement here is
+                # ulp-level, not bitwise: the decode sits in a different fusion
+                # context, so XLA's FMA choices can flip a few stochastic
+                # roundings upstream.
+                g = make_gather_sm(x_hat, fed.wire == "int4")(levels_fl)
+                delta_hat = jax.tree.map(lambda d: combine_fl(d, u),
+                                         _decode_fl(g, norms_fl))
+            else:  # bucketed rs_ag: decode per worker, then rs+ag the f32 mean
+                delta_hat = make_mean_sm(x_hat)(
+                    _decode_fl(levels_fl, norms_fl))
 
-        # (3): server quantization of the averaged update, applied everywhere
-        leaves, treedef = jax.tree.flatten(delta_hat)
-        new_leaves = []
-        lvls, nrms = [], []
-        for i, leaf in enumerate(leaves):
-            u = uniform_like(leaf, _seed_from(skey, 1000 + i))
-            lvl, nrm = encode_tensor(leaf, fed.s0, u, bucket=bucket)
-            lvls.append(lvl)
-            nrms.append(nrm)
-        if fed.wire == "elias" and fed.s0 is not None:
-            # the server multicast rides the same coder: one stream over
-            # the whole flattened update (lossless on levels)
-            flat = _replicated(jnp.concatenate(
-                [l.reshape(-1) for l in lvls]).astype(jnp.int8))
-            words, nb = elias.encode_levels(flat)
-            dec = _replicated(elias.decode_levels(_replicated(words),
-                                                  flat.size))
-            off = 0
-            for i, l in enumerate(lvls):
-                lvls[i] = (dec[off:off + l.size].reshape(l.shape)
-                           .astype(l.dtype))
-                off += l.size
-            elias_bits = (nb if elias_bits is None else elias_bits + nb)
-        for leaf_l, leaf_n, xh in zip(lvls, nrms, jax.tree.leaves(x_hat)):
-            dq = decode_tensor(leaf_l, leaf_n, fed.s0, bucket=bucket)
-            new_leaves.append((xh.astype(jnp.float32)
-                               + gamma * dq).astype(xh.dtype))
-        x_new = jax.tree.unflatten(treedef, new_leaves)
+        with jax.named_scope("server_update"):
+            # (3): server quantization of the averaged update, applied
+            # everywhere
+            leaves, treedef = jax.tree.flatten(delta_hat)
+            new_leaves = []
+            lvls, nrms = [], []
+            for i, leaf in enumerate(leaves):
+                u = uniform_like(leaf, _seed_from(skey, 1000 + i))
+                lvl, nrm = encode_tensor(leaf, fed.s0, u, bucket=bucket)
+                lvls.append(lvl)
+                nrms.append(nrm)
+            if fed.wire == "elias" and fed.s0 is not None:
+                # the server multicast rides the same coder: one stream over
+                # the whole flattened update (lossless on levels)
+                flat = _replicated(jnp.concatenate(
+                    [l.reshape(-1) for l in lvls]).astype(jnp.int8))
+                words, nb = elias.encode_levels(flat)
+                dec = _replicated(elias.decode_levels(_replicated(words),
+                                                      flat.size))
+                off = 0
+                for i, l in enumerate(lvls):
+                    lvls[i] = (dec[off:off + l.size].reshape(l.shape)
+                               .astype(l.dtype))
+                    off += l.size
+                elias_bits = (nb if elias_bits is None else elias_bits + nb)
+            for leaf_l, leaf_n, xh in zip(lvls, nrms, jax.tree.leaves(x_hat)):
+                dq = decode_tensor(leaf_l, leaf_n, fed.s0, bucket=bucket)
+                new_leaves.append((xh.astype(jnp.float32)
+                                   + gamma * dq).astype(xh.dtype))
+            x_new = jax.tree.unflatten(treedef, new_leaves)
         metrics = {"loss": losses.mean(),
                    "delta_norm": jnp.sqrt(sum(
                        jnp.sum(jnp.square(l.astype(jnp.float32)))

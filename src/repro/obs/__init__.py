@@ -1,11 +1,13 @@
 """repro.obs — spans, metrics, and the predicted-vs-measured drift ledger.
 
 Off by default and provably inert: until :func:`enable` flips the global
-switch, every ``trace.span`` returns a shared no-op context manager, every
-global-registry instrument drops its sample after one attribute check, and
-enabling it leaves Plan / RunReport / FaultTrace **bit-identical** (asserted
-by ``tests/unit/test_obs.py`` and hard-gated, with <2% overhead, by
-``benchmarks/obs_bench.py``).
+switch, every ``trace.span`` buffers nothing, every global-registry
+instrument drops its sample after one attribute check, and enabling it
+leaves Plan / RunReport / FaultTrace **bit-identical** (asserted by
+``tests/unit/test_obs.py`` and hard-gated, with <2% overhead, by
+``benchmarks/obs_bench.py``).  Switch or no switch, a span is also a
+``repro.<name>`` ``jax.profiler.TraceAnnotation``, so any profiler trace
+shows it on the device trace's clock (one TraceMe check when none runs).
 
 Quickstart::
 

@@ -1,16 +1,24 @@
-"""Span tracer with Chrome-trace / Perfetto JSON export.
+"""Span tracer: profiler annotations plus a Chrome-trace / Perfetto export.
 
 Spans are recorded only at natural host boundaries (function entry/exit on the
 Python side of a dispatch, queue hand-offs, resolution callbacks) — never from
-inside traced JAX code — so enabling tracing adds **zero extra compiles and
-zero host syncs** to jitted ``lax.while_loop`` paths.
+inside traced JAX code — so tracing adds **zero extra compiles and zero host
+syncs** to jitted ``lax.while_loop`` paths.
 
-The tracer buffers events in a bounded deque under a lock; when the shared
-:class:`~repro.obs.metrics.Switch` is off, ``span()`` hands back a shared no-op
-context manager and nothing is buffered.
+Every ``span(name, **args)`` enters
+``jax.profiler.TraceAnnotation("repro." + name, **args)``: while a profiler
+trace runs, the span lands in its ``.xplane.pb`` on the device trace's clock,
+with ``args`` as event stats; while none runs it costs one TraceMe check.  The
+``repro.`` prefix marks the program's own spans in a trace, as ``bench.``
+marks a benchmark's.  Only when the shared
+:class:`~repro.obs.metrics.Switch` is on does a span also buffer its Chrome
+event, in a bounded deque under a lock.
 
-Export target is the Chrome trace-event JSON format, which Perfetto
-(https://ui.perfetto.dev) opens directly:
+``add_span`` / ``async_span`` / ``instant`` record intervals that have
+already passed (PlanServer queue waits are stamped at resolution): they cannot
+reach the profiler and go to the buffer only.  Export target is the Chrome
+trace-event JSON format, which Perfetto (https://ui.perfetto.dev) opens
+directly:
 
 * ``span()`` / ``add_span()`` emit complete events (``"ph": "X"``) with
   microsecond ``ts``/``dur`` relative to the tracer epoch.
@@ -27,37 +35,35 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .metrics import GLOBAL_SWITCH, Switch
 
-
-class _NoopSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP = _NoopSpan()
+#: the name prefix of the program's spans in a profiler trace
+PREFIX = "repro."
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    """A profiler annotation that also buffers its Chrome event."""
+
+    __slots__ = ("_tracer", "name", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, object]):
         self._tracer = tracer
         self.name = name
         self.args = args
         self._t0 = 0.0
+        self._annotation = TraceAnnotation(PREFIX + name, **args)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.add_span(self.name, self._t0, time.perf_counter(), **self.args)
+        t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._tracer.add_span(self.name, self._t0, t1, **self.args)
         return False
 
 
@@ -73,9 +79,10 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, **args: object):
-        """Context manager timing a host-side region; no-op when disabled."""
+        """Context manager around a host-side region: a ``repro.<name>``
+        profiler annotation, whose Chrome event is buffered when on."""
         if not self.switch.on:
-            return _NOOP
+            return TraceAnnotation(PREFIX + name, **args)
         return _Span(self, name, args)
 
     def add_span(self, name: str, t_start: float, t_end: float, **args: object) -> None:

@@ -26,7 +26,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -182,30 +181,53 @@ def solve_param_opt_batched(problems: Sequence[ParamOptProblem],
                 f"and {structure_signature(p)}; group instances by "
                 f"(m, family, N) first")
     B = len(problems)
+    fused = backend == "jnp-fused"
+    with _trace.span("gia.solve", backend=backend, rows=B,
+                     m=str(problems[0].m.value)):
+        if fused:
+            results = _solve_fused(problems, z0s, tol, max_iter, pad_to)
+        else:
+            results = _solve_host_loop(problems, z0s, tol, max_iter, backend,
+                                       verbose)
+    _record_solve(backend, B, results, pad_to if fused else 0)
+    if joint_restart and problems[0].m is Objective.JOINT:
+        results = _joint_restart_batched(problems, results, tol, max_iter,
+                                         backend, pad_to=pad_to)
+    return results
+
+
+def _solve_fused(problems: List[ParamOptProblem], z0s, tol: float,
+                 max_iter: int, pad_to: int) -> List[GIAResult]:
+    """One fused device dispatch (:mod:`repro.opt.gia_jax`), then the
+    host-side finish under a ``gia.finalize`` span that carries the
+    dispatch's lockstep counters."""
+    from .gia_jax import solve_gia_fused
+
+    out = solve_gia_fused(problems, z0s, tol, max_iter, pad_to=pad_to)
+    active = int(out.active_iters.sum())
+    if _OBS_ON.on:
+        _METRICS.counter("gia.lockstep_iters",
+                         backend="jnp-fused").inc(out.lockstep_iters)
+        _METRICS.counter("gia.active_row_iters",
+                         backend="jnp-fused").inc(active)
+    with _trace.span("gia.finalize", lockstep_iters=out.lockstep_iters,
+                     active_row_iters=active, rows=len(problems)):
+        return [_finalize(p, np.asarray(out.z[i], dtype=np.float64),
+                          out.history(i), bool(out.converged[i]))
+                for i, p in enumerate(problems)]
+
+
+def _solve_host_loop(problems: List[ParamOptProblem], z0s, tol: float,
+                     max_iter: int, backend: str,
+                     verbose: bool) -> List[GIAResult]:
+    """The outer loop on the host, each iteration's GP solves batched."""
+    B = len(problems)
     if z0s is None:
         zs = [p.z_init() for p in problems]
     else:
         zs = [p.z_init() if z is None
               else np.asarray(z, dtype=np.float64).copy()
               for p, z in zip(problems, z0s)]
-    _t0 = time.perf_counter() if _OBS_ON.on else 0.0
-    if backend == "jnp-fused":
-        from .gia_jax import solve_gia_fused
-        results = [
-            _finalize(p, np.asarray(z, dtype=np.float64), history, conv)
-            for p, (z, history, conv)
-            in zip(problems, solve_gia_fused(problems, zs, tol, max_iter,
-                                             pad_to=pad_to))]
-        if _OBS_ON.on:
-            _trace.add_span("gia.solve", _t0, time.perf_counter(),
-                            backend=backend, rows=B,
-                            m=str(problems[0].m.value))
-            _record_solve(backend, B, results, pad_to)
-        if joint_restart and problems[0].m is Objective.JOINT:
-            results = _joint_restart_batched(problems, results, tol,
-                                             max_iter, backend,
-                                             pad_to=pad_to)
-        return results
     structure = GPStructure(problems[0])
     history: List[List[float]] = [[] for _ in range(B)]
     converged = [False] * B
@@ -240,17 +262,9 @@ def solve_param_opt_batched(problems: Sequence[ParamOptProblem],
             if step < tol:
                 converged[i] = True
                 active[i] = False
-    results = [_finalize(p, np.asarray(zs[i], dtype=np.float64), history[i],
-                         converged[i])
-               for i, p in enumerate(problems)]
-    if _OBS_ON.on:
-        _trace.add_span("gia.solve", _t0, time.perf_counter(),
-                        backend=backend, rows=B, m=str(problems[0].m.value))
-        _record_solve(backend, B, results, pad_to=0)
-    if joint_restart and problems[0].m is Objective.JOINT:
-        results = _joint_restart_batched(problems, results, tol, max_iter,
-                                         backend)
-    return results
+    return [_finalize(p, np.asarray(zs[i], dtype=np.float64), history[i],
+                      converged[i])
+            for i, p in enumerate(problems)]
 
 
 # ---------------------------------------------------------------------------

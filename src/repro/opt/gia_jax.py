@@ -31,8 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +47,7 @@ from .problems import Objective
 from .refresh import RefreshPlan, make_project, make_refresh
 from .structure import PAD_LOGC
 
-__all__ = ["solve_gia_fused", "trace_count", "TRACE_COUNTS",
+__all__ = ["FusedSolve", "solve_gia_fused", "trace_count", "TRACE_COUNTS",
            "compile_cache_info", "compile_cache_clear"]
 #: host-loop stall budget, verbatim (gia.solve_param_opt_batched)
 _STALL_MAX = 8
@@ -183,7 +182,8 @@ def _compiled(m_value: str, n: int, m_cons: int, seg_bytes: bytes,
 
         def row_body(z_aug, z_exp, z_out, c_logc, c_A, p1, t, p1_stage,
                      newton_it, gia_it, stall, conv, active, hist, nh,
-                     o_logc, o_A, sk_logc, sk_A, a):
+                     active_it, o_logc, o_A, sk_logc, sk_A, a):
+            active_it = jnp.where(active, active_it + 1, active_it)
             logc = jnp.concatenate([sk_logc, c_logc])
             A = jnp.concatenate([sk_A, c_A], axis=0)
             p1f = jnp.where(p1, 1.0, 0.0)
@@ -308,7 +308,8 @@ def _compiled(m_value: str, n: int, m_cons: int, seg_bytes: bytes,
                                  jnp.where(need_p1, g0 + 1.0, 0.0)[None]]),
                 z_aug)
             return (z_aug, z_exp, z_out, c_logc, c_A, p1, t, p1_stage,
-                    newton_it, gia_it, stall, conv, active, hist, nh)
+                    newton_it, gia_it, stall, conv, active, hist, nh,
+                    active_it)
 
         row_body_v = jax.vmap(row_body)
 
@@ -339,9 +340,11 @@ def _compiled(m_value: str, n: int, m_cons: int, seg_bytes: bytes,
                 jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32),
                 jnp.zeros(B, jnp.int32), jnp.zeros(B, dtype=bool),
                 jnp.ones(B, dtype=bool), jnp.full((B, max_iter), jnp.nan),
-                jnp.zeros(B, jnp.int32))
-        rows, _ = lax.while_loop(cond, body, (rows, jnp.int32(0)))
-        return rows[2], rows[11], rows[13], rows[14]
+                jnp.zeros(B, jnp.int32), jnp.zeros(B, jnp.int32))
+        rows, trips = lax.while_loop(cond, body, (rows, jnp.int32(0)))
+        # (z, converged, history, history length, per-row active lockstep
+        # iterations, the loop's trip count)
+        return rows[2], rows[11], rows[13], rows[14], rows[15], trips
 
     # donate the starting points' buffer (a no-op on CPU, which has no
     # donation support — avoid the warning there)
@@ -349,51 +352,67 @@ def _compiled(m_value: str, n: int, m_cons: int, seg_bytes: bytes,
     return jax.jit(run, donate_argnums=donate)
 
 
-def solve_gia_fused(problems: Sequence, z0s: Sequence[np.ndarray],
-                    tol: float, max_iter: int, pad_to: int = 0
-                    ) -> List[Tuple[np.ndarray, List[float], bool]]:
-    """Run the fused lockstep GIA; returns per-instance
-    ``(z, history, converged)`` for :func:`repro.opt.gia._finalize`.
+@dataclasses.dataclass
+class FusedSolve:
+    """One fused dispatch's outputs for its real rows (padding dropped), as
+    read at the dispatch's single host sync."""
 
-    ``pad_to > len(problems)`` pads the device batch to a fixed row count by
-    replicating row 0 (padding rows solve normally and are discarded), so
-    every dispatch of a structure signature shares one jitted shape — a
-    serving loop whose micro-batches vary in size still pays exactly one
-    trace/compile per signature.  Padding rows cannot stretch the lockstep:
-    the flat state machine's trip count is the max of per-row totals, and a
-    duplicate of row 0 finishes exactly when row 0 does.
-    """
-    plan = RefreshPlan.build(problems)
-    fn = _compiled(plan.m.value, plan.n, plan.m_cons, plan.seg.tobytes(),
-                   plan.caps, plan.i_x0, int(max_iter), plan.sampled)
-    z0 = np.stack([np.asarray(z, dtype=np.float64) for z in z0s])
-    pad = int(pad_to) - len(problems)
-    if pad > 0:
-        def _pad(a):
-            return np.concatenate([a, np.repeat(a[:1], pad, axis=0)])
-        z0 = _pad(z0)
-        plan = dataclasses.replace(
-            plan, obj_logc=_pad(plan.obj_logc), obj_A=_pad(plan.obj_A),
-            skel_logc=_pad(plan.skel_logc), skel_A=_pad(plan.skel_A),
-            arrays={k: _pad(v) for k, v in plan.arrays.items()})
-    _t0 = time.perf_counter() if _OBS_ON.on else 0.0
-    with jax.enable_x64(True):
-        z, conv, hist, nh = fn(float(tol), z0,
-                               plan.obj_logc, plan.obj_A, plan.skel_logc,
-                               plan.skel_A, plan.arrays)
-        # the single host sync of the whole solve
-        z, conv, hist, nh = (np.asarray(z), np.asarray(conv),
-                             np.asarray(hist), np.asarray(nh))
-    if _OBS_ON.on:
-        # stamped strictly after the sync above — the span brackets the
-        # dispatch+sync the solve already paid, it never adds one
-        _otrace.add_span("gia.fused_dispatch", _t0, time.perf_counter(),
-                         rows=len(problems), padded=int(z0.shape[0]),
-                         sig=str(plan.signature_key)[:160])
-    out = []
-    for i in range(len(problems)):
-        col = hist[i]
+    z: np.ndarray                  # (rows, n) final points
+    converged: np.ndarray          # (rows,) bool
+    hist: np.ndarray               # (rows, max_iter) objective, NaN = none
+    n_hist: np.ndarray             # (rows,) accepted GIA iterations
+    active_iters: np.ndarray       # (rows,) lockstep iterations row was live
+    lockstep_iters: int            # the while loop's trip count
+
+    def history(self, i: int) -> List[float]:
+        col = self.hist[i]
         history = [float(v) for v in col[~np.isnan(col)]]
-        assert len(history) == int(nh[i])
-        out.append((z[i], history, bool(conv[i])))
-    return out
+        assert len(history) == int(self.n_hist[i])
+        return history
+
+
+def solve_gia_fused(problems: Sequence, z0s: Optional[Sequence], tol: float,
+                    max_iter: int, pad_to: int = 0) -> FusedSolve:
+    """Run the fused lockstep GIA over ``problems`` in one dispatch.
+
+    ``z0s`` holds a log-space start per row, or ``None`` (for all rows or
+    one) for the row's cold ``z_init()``.  ``pad_to > len(problems)`` pads
+    the device batch to a fixed row count by replicating row 0 (padding
+    rows solve normally and are discarded), so every dispatch of a
+    structure signature shares one jitted shape — a serving loop whose
+    micro-batches vary in size still pays exactly one trace/compile per
+    signature.  Padding rows cannot stretch the lockstep: the flat state
+    machine's trip count is the max of per-row totals, and a duplicate of
+    row 0 finishes exactly when row 0 does.
+
+    Host spans ``gia.pack`` (start points, refresh plan, padding) and
+    ``gia.fused_dispatch`` (the call and its single sync) bracket the work.
+    """
+    B = len(problems)
+    padded = max(B, int(pad_to))
+    with _otrace.span("gia.pack", rows=B, padded=padded):
+        z0 = np.stack([p.z_init() if z0s is None or z0s[i] is None
+                       else np.asarray(z0s[i], dtype=np.float64)
+                       for i, p in enumerate(problems)])
+        plan = RefreshPlan.build(problems)
+        fn = _compiled(plan.m.value, plan.n, plan.m_cons, plan.seg.tobytes(),
+                       plan.caps, plan.i_x0, int(max_iter), plan.sampled)
+        if padded > B:
+            def _pad(a):
+                return np.concatenate([a, np.repeat(a[:1], padded - B,
+                                                    axis=0)])
+            z0 = _pad(z0)
+            plan = dataclasses.replace(
+                plan, obj_logc=_pad(plan.obj_logc), obj_A=_pad(plan.obj_A),
+                skel_logc=_pad(plan.skel_logc), skel_A=_pad(plan.skel_A),
+                arrays={k: _pad(v) for k, v in plan.arrays.items()})
+    with _otrace.span("gia.fused_dispatch", rows=B, padded=padded,
+                      sig=str(plan.signature_key)[:160]):
+        with jax.enable_x64(True):
+            out = fn(float(tol), z0, plan.obj_logc, plan.obj_A,
+                     plan.skel_logc, plan.skel_A, plan.arrays)
+            # the single host sync of the whole solve
+            z, conv, hist, nh, active, trips = (np.asarray(a) for a in out)
+    return FusedSolve(z=z[:B], converged=conv[:B], hist=hist[:B],
+                      n_hist=nh[:B], active_iters=active[:B],
+                      lockstep_iters=int(trips))

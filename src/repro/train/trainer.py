@@ -20,6 +20,7 @@ from ..core.step_rules import StepRule
 from ..fed import sharding as SH
 from ..fed.runtime import FedConfig, make_round_fn
 from ..obs import REGISTRY as _METRICS
+from ..obs import trace as _trace
 from ..obs.metrics import GLOBAL_SWITCH as _OBS_ON
 from . import checkpoint as CKPT
 
@@ -88,7 +89,6 @@ class GenQSGDTrainer:
         # already computes; disabled runs pay one boolean check per round
         obs_on = _OBS_ON.on
         if obs_on:
-            _round_h = _METRICS.histogram("run.round_s", backend="spmd")
             _htvar_h = _METRICS.histogram("run.ht_weight_var", backend="spmd")
             _bits_c = _METRICS.counter("run.wire_bits", backend="spmd",
                                        codec=fed.wire)
@@ -113,17 +113,17 @@ class GenQSGDTrainer:
             elif idx is not None:   # sampling only: the historical HT path
                 u = cohort_weights(idx, pi, fed.n_workers, fed.agg_weights)
                 comm_mbits = round_comm_bits(fed, dim, cohort=idx) / 1e6
-            if u is not None:
-                state.params, metrics = self._round(
-                    state.params, batch, rkey, jnp.float32(gammas[r]),
-                    jnp.asarray(u, jnp.float32))
-            else:
-                state.params, metrics = self._round(
-                    state.params, batch, rkey, jnp.float32(gammas[r]))
+            # the dispatch only: the round runs on asynchronously, and no
+            # block_until_ready is added (observing must not serialize it)
+            with _trace.span("trainer.round", round=r):
+                if u is not None:
+                    state.params, metrics = self._round(
+                        state.params, batch, rkey, jnp.float32(gammas[r]),
+                        jnp.asarray(u, jnp.float32))
+                else:
+                    state.params, metrics = self._round(
+                        state.params, batch, rkey, jnp.float32(gammas[r]))
             if obs_on:
-                # async dispatch: host loop time per round, never an added
-                # block_until_ready (observing must not serialize the mesh)
-                _round_h.observe(time.time() - t0)
                 _rounds_c.inc()
                 _bits_c.inc(comm_mbits * 1e6)
                 if u is not None:
@@ -134,11 +134,13 @@ class GenQSGDTrainer:
                     _htvar_h.observe(
                         sum((v - _mu) ** 2 for v in _ul) / len(_ul))
             if r % log_every == 0 or r == state.round + n_rounds - 1:
-                rec = {"round": r, "gamma": float(gammas[r]),
-                       "loss": float(metrics["loss"]),
-                       "delta_norm": float(metrics["delta_norm"]),
-                       "comm_mbits": comm_mbits,
-                       "dt": time.time() - t0}
+                # the logged round's host sync
+                with _trace.span("trainer.log", round=r):
+                    rec = {"round": r, "gamma": float(gammas[r]),
+                           "loss": float(metrics["loss"]),
+                           "delta_norm": float(metrics["delta_norm"]),
+                           "comm_mbits": comm_mbits,
+                           "dt": time.time() - t0}
                 if eval_fn is not None:
                     rec.update(eval_fn(state.params))
                 state.history.append(rec)

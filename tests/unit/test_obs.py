@@ -5,7 +5,7 @@ Covers the ISSUE-10 acceptance bar:
 
   * **inert when off** — with the global switch down (the default), every
     instrument drops its sample after one attribute check, ``span()``
-    returns a shared no-op, and nothing is buffered;
+    is only a profiler annotation, and nothing is buffered;
   * **observer effect = none** — enabling observability leaves the Plan,
     the RunReport (modulo its wall-clock field — real time differs
     between *any* two runs) and the FaultTrace bit-identical across an
@@ -138,16 +138,60 @@ def test_prometheus_and_snapshot():
 # ---------------------------------------------------------------------------
 # tracer
 # ---------------------------------------------------------------------------
-def test_tracer_noop_when_off():
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a profiler trace; -> the trace's ``repro.*`` host
+    events as (name, stats) in start order."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    evs = [(ev.start_ns, ev.name, dict(ev.stats))
+           for plane in ProfileData.from_file(path).planes
+           for line in plane.lines for ev in line.events
+           if ev.name.startswith("repro.")]
+    return [(n, st) for _, n, st in sorted(evs, key=lambda e: e[0])]
+
+
+def test_tracer_noop_when_off(tmp_path):
     tr = Tracer(Switch(False))
-    with tr.span("a"):
-        pass
-    tr.add_span("b", 0.0, 1.0)
-    tr.async_span("c", 1, 0.0, 1.0)
-    tr.instant("d")
+
+    def work():
+        with tr.span("a", rows=3, sig="g"):
+            pass
+        tr.add_span("b", 0.0, 1.0)
+        tr.async_span("c", 1, 0.0, 1.0)
+        tr.instant("d")
+
+    # off, a span is still a profiler annotation (with its arguments); the
+    # past intervals cannot reach the profiler; nothing is buffered
+    assert _profiled(tmp_path, work) == [("repro.a", {"rows": 3, "sig": "g"})]
     assert len(tr) == 0
-    # the no-op context manager is shared: zero per-call allocation
-    assert tr.span("a") is tr.span("b")
+
+
+@pytest.mark.parametrize("on", [False, True])
+def test_span_lands_in_profiler_trace(tmp_path, on):
+    """``obs.span`` writes ``repro.<name>`` with its arguments into any
+    profiler trace, obs on or off; only when on does it also buffer."""
+    if on:
+        obs.enable(reset=True)
+
+    def work():
+        with obs.span("outer", n=2):
+            with obs.span("inner"):
+                pass
+
+    assert _profiled(tmp_path, work) == [("repro.outer", {"n": 2}),
+                                         ("repro.inner", {})]
+    assert [e["name"] for e in obs.TRACER.events()] == \
+        (["inner", "outer"] if on else [])
 
 
 def test_tracer_spans_and_chrome_export(tmp_path):
@@ -303,7 +347,7 @@ def test_enabling_obs_adds_no_fused_compile():
     assert sum(gia_jax.TRACE_COUNTS.values()) == warm, \
         "enabling obs re-traced the fused engine"
     assert p_on == p_off
-    # the dispatch span was stamped after the solve's own host sync
+    # the dispatch span (the call and its one host sync) was buffered
     names = {e["name"] for e in obs.TRACER.events()}
     assert "gia.fused_dispatch" in names
 

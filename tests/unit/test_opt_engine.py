@@ -251,6 +251,42 @@ def test_fused_pad_to_rows_bitwise_unchanged():
         assert (a.K0, a.B, a.E) == (b.K0, b.B, b.E)
 
 
+def test_fused_lockstep_counters():
+    """The fused program counts its while loop's trips and, per row, the
+    lockstep iterations in which the row was still active: the loop runs
+    until its slowest row stops, so the trip count is the largest row
+    count.  Padding rows are not reported, and the counters reach the
+    ``gia.finalize`` span and, with obs on, the registry."""
+    from repro import obs
+    from repro.opt.gia_jax import solve_gia_fused
+
+    probs = _problems("genqsgd", Objective.CONSTANT,
+                      budgets=(0.22, 0.25, 0.3, 0.6))
+    out = solve_gia_fused(probs, None, 1e-4, 60, pad_to=8)
+    assert out.active_iters.shape == (4,) and out.z.shape[0] == 4
+    assert out.lockstep_iters == int(out.active_iters.max())
+    obs.enable(reset=True)
+    try:
+        res = solve_param_opt_batched(probs, backend="jnp-fused", pad_to=8)
+        fin = [e for e in obs.TRACER.events() if e["name"] == "gia.finalize"]
+        snap = obs.REGISTRY.snapshot()
+    finally:
+        obs.disable()
+        obs.TRACER.clear()
+        obs.REGISTRY.reset()
+    assert [e["args"] for e in fin] == [{
+        "lockstep_iters": out.lockstep_iters,
+        "active_row_iters": int(out.active_iters.sum()), "rows": 4}]
+    assert snap['gia.lockstep_iters{backend="jnp-fused"}'] == \
+        out.lockstep_iters
+    assert snap['gia.active_row_iters{backend="jnp-fused"}'] == \
+        int(out.active_iters.sum())
+    for i, r in enumerate(res):
+        assert np.array_equal(r.z, out.z[i]) and r.history == out.history(i)
+        # every GIA iteration takes at least one lockstep Newton step
+        assert 1 <= r.iterations <= out.active_iters[i]
+
+
 def test_optimize_fused_compile_cache_is_process_level():
     """Repeated ``Scenario.optimize(backend='jnp-fused')`` calls across
     *distinct* Scenario objects reuse the compiled fused executable: the
