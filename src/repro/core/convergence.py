@@ -2,7 +2,10 @@
 
 All functions are NumPy-float implementations (they feed the offline GP-based
 parameter optimizer, not the device-side training step) and accept vectorized
-``K_n``.
+``K_n``.  ``c_constant``, ``c_exponential`` and ``c_diminishing`` also take a
+grid of points at once: ``Kn`` of shape (G, N) with ``B`` (and the step size)
+of shape (G,), against an ndarray ``K0`` ladder of shape (L,), give a (G, L)
+surface whose row g holds, bit for bit, what grid point g gives alone.
 
 Notation:
   K0        : number of global iterations
@@ -24,17 +27,42 @@ __all__ = ["MLProblemConstants", "coefficients", "c_arbitrary", "c_constant",
            "c_exponential", "c_diminishing", "c_m"]
 
 
+def per_point(Kn: np.ndarray, x):
+    """``x`` (one value per grid point) as a column against the K0 ladder
+    where ``Kn`` carries a leading grid axis, (G, N); unchanged otherwise."""
+    return np.asarray(x)[..., None] if Kn.ndim == 2 else x
+
+
+def _square(x):
+    """``x ** 2`` as a scalar gets it (``pow``), element by element.  NumPy
+    squares an array by a multiply, which can land an ulp away, so a grid
+    row would not repeat its point's bits."""
+    if np.ndim(x) == 0:
+        return x ** 2
+    return np.array([v ** 2 for v in np.ravel(x).tolist()]).reshape(
+        np.shape(x))
+
+
 def _weighted_blocks(Kn: np.ndarray, q_pairs: np.ndarray, eps):
     """``(sum_n eps_n K_n, sum_n q_n (eps_n K_n)^2)`` — the two aggregation
-    blocks of the bound.  ``eps=None`` (uniform weights) takes the exact
-    historical arithmetic, so GenQSGD results stay bitwise unchanged;
-    weighted families (``eps_n = N w_n``, :mod:`repro.families`) reweight
-    the effective local work and the quantization variance per worker."""
+    blocks of the bound, summed over the workers (the last axis).
+    ``eps=None`` (uniform weights) takes the exact historical arithmetic,
+    so GenQSGD results stay bitwise unchanged; weighted families
+    (``eps_n = N w_n``, :mod:`repro.families`) reweight the effective local
+    work and the quantization variance per worker."""
     if eps is None:
-        return Kn.sum(), (q_pairs * Kn**2).sum()
+        return Kn.sum(axis=-1), (q_pairs * Kn**2).sum(axis=-1)
     e = np.asarray(eps, dtype=np.float64)
     eK = e * Kn
-    return eK.sum(), (q_pairs * eK**2).sum()
+    return eK.sum(axis=-1), (q_pairs * eK**2).sum(axis=-1)
+
+
+def _grid_terms(Kn, B, q_pairs, eps):
+    """``(sum_K, qK2, max_n K_n ** 2, B)`` per point, as columns against
+    the K0 ladder where ``Kn`` is a (G, N) grid."""
+    sum_K, qK2 = _weighted_blocks(Kn, q_pairs, eps)
+    kmax2 = _square(Kn.max(axis=-1))
+    return tuple(per_point(Kn, x) for x in (sum_K, qK2, kmax2, B))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,22 +108,25 @@ def c_arbitrary(K0, Kn, B, gammas, c, q_pairs, eps=None) -> float:
 
 def c_constant(K0, Kn, B, gamma_c, c, q_pairs, eps=None):
     """C_C — eq. (11).  Broadcasts over an ndarray ``K0`` (the feasibility
-    grid search evaluates whole K0 ladders at once); scalar in, float out."""
+    grid search evaluates whole K0 ladders at once) and over a grid of
+    points (``gamma_c`` may then be one per point); scalar in, float out."""
     c1, c2, c3, c4 = c
     Kn = np.asarray(Kn, dtype=np.float64)
     q_pairs = np.asarray(q_pairs, dtype=np.float64)
-    sum_K, qK2 = _weighted_blocks(Kn, q_pairs, eps)
+    sum_K, qK2, kmax2, B = _grid_terms(Kn, B, q_pairs, eps)
+    if np.ndim(gamma_c):
+        gamma_c = per_point(Kn, gamma_c)
     out = (
         c1 / (gamma_c * K0 * sum_K)
-        + c2 * gamma_c**2 * Kn.max() ** 2
+        + c2 * _square(gamma_c) * kmax2
         + c3 * gamma_c / B
         + c4 * gamma_c * qK2 / sum_K
     )
-    return out if np.ndim(K0) else float(out)
+    return out if np.ndim(out) else float(out)
 
 
 def c_exponential(K0, Kn, B, gamma_e, rho_e, c, q_pairs, eps=None):
-    """C_E — eq. (13).  Broadcasts over an ndarray ``K0``."""
+    """C_E — eq. (13).  Broadcasts over an ndarray ``K0`` and a grid."""
     c1, c2, c3, c4 = c
     Kn = np.asarray(Kn, dtype=np.float64)
     q_pairs = np.asarray(q_pairs, dtype=np.float64)
@@ -103,19 +134,19 @@ def c_exponential(K0, Kn, B, gamma_e, rho_e, c, q_pairs, eps=None):
     a2 = gamma_e**2 / (1.0 + rho_e + rho_e**2)
     a3 = gamma_e / (1.0 + rho_e)
     r1 = rho_e**K0
-    sum_K, qK2 = _weighted_blocks(Kn, q_pairs, eps)
+    sum_K, qK2, kmax2, B = _grid_terms(Kn, B, q_pairs, eps)
     out = (
         a1 * c1 / ((1.0 - r1) * sum_K)
-        + a2 * c2 * (1.0 - rho_e ** (3 * K0)) / (1.0 - r1) * Kn.max() ** 2
+        + a2 * c2 * (1.0 - rho_e ** (3 * K0)) / (1.0 - r1) * kmax2
         + a3 * (1.0 - rho_e ** (2 * K0)) / (1.0 - r1)
         * (c3 / B + c4 * qK2 / sum_K)
     )
-    return out if np.ndim(K0) else float(out)
+    return out if np.ndim(out) else float(out)
 
 
 def c_diminishing(K0, Kn, B, gamma_d, rho_d, c, q_pairs, eps=None):
     """C_D — eq. (16) (upper bound used for optimization).  Broadcasts over
-    an ndarray ``K0``."""
+    an ndarray ``K0`` and a grid."""
     c1, c2, c3, c4 = c
     Kn = np.asarray(Kn, dtype=np.float64)
     q_pairs = np.asarray(q_pairs, dtype=np.float64)
@@ -124,14 +155,14 @@ def c_diminishing(K0, Kn, B, gamma_d, rho_d, c, q_pairs, eps=None):
         + (rho_d**2 * gamma_d**2) / (2.0 * (rho_d + 1.0) ** 2)
     b3 = rho_d * gamma_d / (rho_d + 1.0) ** 2 + rho_d * gamma_d / (rho_d + 1.0)
     logt = np.log((K0 + rho_d + 1.0) / (rho_d + 1.0))
-    sum_K, qK2 = _weighted_blocks(Kn, q_pairs, eps)
+    sum_K, qK2, kmax2, B = _grid_terms(Kn, B, q_pairs, eps)
     out = (
         b1 * c1 / (logt * sum_K)
-        + b2 * c2 * Kn.max() ** 2 / logt
+        + b2 * c2 * kmax2 / logt
         + b3 * c3 / (B * logt)
         + b3 * c4 * qK2 / (logt * sum_K)
     )
-    return out if np.ndim(K0) else float(out)
+    return out if np.ndim(out) else float(out)
 
 
 def c_m(m: str, K0, Kn, B, rule, c, q_pairs, eps=None) -> float:

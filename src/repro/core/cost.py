@@ -25,6 +25,7 @@ import numpy as np
 
 from ..compress import make_codec, q_pair
 from ..roofline.analysis import peaks
+from .convergence import per_point
 
 __all__ = ["EdgeSystem", "time_cost", "energy_cost"]
 
@@ -251,7 +252,9 @@ class EdgeSystem:
 
 
 def time_cost(sys: EdgeSystem, K0, Kn, B, worst_case: bool = False):
-    """T(K, B) — eq. (17).  Broadcasts over an ndarray ``K0``.
+    """T(K, B) — eq. (17).  Broadcasts over an ndarray ``K0``, and over a
+    grid of points: ``Kn`` (G, N) and ``B`` (G,) give a (G, L) surface
+    against an (L,) ``K0`` ladder, row g bitwise point g's alone.
 
     ``worst_case=True`` prices the derated worker capabilities
     ``F_n (1 - freq_margin)`` / ``r_n (1 - rate_margin)`` — identical to
@@ -260,12 +263,14 @@ def time_cost(sys: EdgeSystem, K0, Kn, B, worst_case: bool = False):
     Kn = np.asarray(Kn, dtype=np.float64)
     ct = sys.comp_time_coeff_wc if worst_case else sys.comp_time_coeff
     tau = sys.comm_time_wc if worst_case else sys.comm_time
-    out = K0 * (B * np.max(ct * Kn) + tau)
-    return out if np.ndim(K0) else float(out)
+    slowest = per_point(Kn, np.max(ct * Kn, axis=-1))
+    out = K0 * (per_point(Kn, B) * slowest + tau)
+    return out if np.ndim(out) else float(out)
 
 
 def energy_cost(sys: EdgeSystem, K0, Kn, B, pi=None):
-    """E(K, B) — eq. (18).  Broadcasts over an ndarray ``K0``.
+    """E(K, B) — eq. (18).  Broadcasts over an ndarray ``K0`` and over a
+    grid of points, as :func:`time_cost` does.
 
     ``pi`` (per-worker inclusion probabilities under client sampling)
     turns this into the *expected* energy over cohort draws: each worker's
@@ -273,12 +278,14 @@ def energy_cost(sys: EdgeSystem, K0, Kn, B, pi=None):
     historical full-participation arithmetic, verbatim.
     """
     Kn = np.asarray(Kn, dtype=np.float64)
+    B = per_point(Kn, B)
     if pi is None:
-        out = K0 * (B * np.sum(sys.comp_energy_coeff * Kn)
-                    + sys.const_energy)
+        work = np.sum(sys.comp_energy_coeff * Kn, axis=-1)
+        out = K0 * (B * per_point(Kn, work) + sys.const_energy)
     else:
         pi = np.asarray(pi, dtype=np.float64)
-        out = K0 * (B * np.sum(sys.comp_energy_coeff * pi * Kn)
+        work = np.sum(sys.comp_energy_coeff * pi * Kn, axis=-1)
+        out = K0 * (B * per_point(Kn, work)
                     + sys.server_energy
                     + np.sum(sys.comm_energy_coeff * pi))
-    return out if np.ndim(K0) else float(out)
+    return out if np.ndim(out) else float(out)

@@ -386,14 +386,20 @@ def solve_gia_fused(problems: Sequence, z0s: Optional[Sequence], tol: float,
     row 0 finishes exactly when row 0 does.
 
     Host spans ``gia.pack`` (start points, refresh plan, padding) and
-    ``gia.fused_dispatch`` (the call and its single sync) bracket the work.
+    ``gia.fused_dispatch`` (the call and its single sync) bracket the work;
+    ``gia.z_init``, inside ``gia.pack``, holds the start points, with the
+    rows started cold and the grid points their searches priced.
     """
     B = len(problems)
     padded = max(B, int(pad_to))
+    z0s = [None] * B if z0s is None else list(z0s)
+    cold = [p for p, z in zip(problems, z0s) if z is None]
     with _otrace.span("gia.pack", rows=B, padded=padded):
-        z0 = np.stack([p.z_init() if z0s is None or z0s[i] is None
-                       else np.asarray(z0s[i], dtype=np.float64)
-                       for i, p in enumerate(problems)])
+        with _otrace.span("gia.z_init", rows=len(cold), grid_points=sum(
+                p.init_grid_points for p in cold)):
+            z0 = np.stack([p.z_init() if z is None
+                           else np.asarray(z, dtype=np.float64)
+                           for p, z in zip(problems, z0s)])
         plan = RefreshPlan.build(problems)
         fn = _compiled(plan.m.value, plan.n, plan.m_cons, plan.seg.tobytes(),
                        plan.caps, plan.i_x0, int(max_iter), plan.sampled)

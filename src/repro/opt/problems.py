@@ -680,36 +680,62 @@ class ParamOptProblem:
             cls._K0_LADDER = np.asarray(ks, dtype=np.float64)
         return cls._K0_LADDER
 
+    def _init_combos(self) -> List[Tuple[Optional[float], int, int]]:
+        """z_init's search grid: (trial gamma (m=J only), B, K_n) points."""
+        gamma_grid = ([None] if self.m is not Objective.JOINT
+                      else [0.5 / self.consts.L, 0.1 / self.consts.L,
+                            0.01 / self.consts.L, 1.0 / self.consts.L])
+        B_grid = (1, 2, 4, 8, 16, 32, 64, 128)
+        K_grid = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32)
+        return [(gam, Bv, Kv) for gam in gamma_grid for Bv in B_grid
+                for Kv in K_grid]
+
+    def _init_S_levels(self) -> List[Optional[float]]:
+        """The cohort sizes z_init prices its grid at.  Free-S problems
+        search a halving ladder: the local GIA polishes within the basin
+        this seed lands in, so the seed must compare S levels globally
+        (the energy-optimal cohort can sit far below the cap)."""
+        if self._i_S is None:
+            return [None]
+        cap = max(1.0, float(np.floor(
+            self.sampling.s_cap(self.sys.N) + 1e-9)))
+        S_levels, sv = [], cap
+        while True:
+            S_levels.append(sv)
+            if sv <= 1.0:
+                return S_levels
+            sv = float(np.ceil(sv / 2.0))
+
+    @property
+    def init_grid_points(self) -> int:
+        """Grid points (x cohort sizes) :meth:`z_init` prices, each over
+        the whole K0 ladder."""
+        return len(self._init_combos()) * len(self._init_S_levels())
+
     def _grid_CTE(self, ks: np.ndarray, Kn: np.ndarray, B: np.ndarray,
                   gam_arr: Optional[np.ndarray],
                   S0: Optional[float] = None):
-        """C/T/E surfaces over (grid point, K0 ladder) — evaluated with the
-        very same :mod:`repro.core` closed forms :meth:`evaluate` uses
-        (they broadcast over the ladder axis), so the feasibility search
-        can never drift from the true cost model.  ``S0`` prices the
-        surfaces at a concrete cohort size (free-S problems only)."""
+        """C/T/E surfaces over (grid point, K0 ladder), (G, L) each — one
+        call of each of the very :mod:`repro.core` closed forms
+        :meth:`evaluate` uses, over ``Kn`` (G, N) and ``B`` (G,), so the
+        feasibility search can never drift from the true cost model: row g
+        is bitwise what point g gives alone.  ``S0`` prices the surfaces at
+        a concrete cohort size (free-S problems only)."""
         from ..core import convergence as conv
         from ..core.cost import energy_cost, time_cost
         c, qp = self._conv_coeffs(S0)
-        pi = self._pi_at(S0)
         eps = self._agg_eps
-        G, L = Kn.shape[0], ks.shape[0]
-        C = np.empty((G, L))
-        T = np.empty((G, L))
-        E = np.empty((G, L))
-        for g in range(G):
-            if self.m is Objective.EXPONENTIAL:
-                C[g] = conv.c_exponential(ks, Kn[g], B[g], self.gamma,
-                                          self.rho, c, qp, eps)
-            elif self.m is Objective.DIMINISHING:
-                C[g] = conv.c_diminishing(ks, Kn[g], B[g], self.gamma,
-                                          self.rho, c, qp, eps)
-            else:   # CONSTANT, or JOINT at the grid's trial gamma
-                gam = (gam_arr[g] if self.m is Objective.JOINT
-                       else self.gamma)
-                C[g] = conv.c_constant(ks, Kn[g], B[g], gam, c, qp, eps)
-            T[g] = time_cost(self.sys, ks, Kn[g], B[g], worst_case=True)
-            E[g] = energy_cost(self.sys, ks, Kn[g], B[g], pi=pi)
+        if self.m is Objective.EXPONENTIAL:
+            C = conv.c_exponential(ks, Kn, B, self.gamma, self.rho, c, qp,
+                                   eps)
+        elif self.m is Objective.DIMINISHING:
+            C = conv.c_diminishing(ks, Kn, B, self.gamma, self.rho, c, qp,
+                                   eps)
+        else:   # CONSTANT, or JOINT at each point's trial gamma
+            gam = gam_arr if self.m is Objective.JOINT else self.gamma
+            C = conv.c_constant(ks, Kn, B, gam, c, qp, eps)
+        T = time_cost(self.sys, ks, Kn, B, worst_case=True)
+        E = energy_cost(self.sys, ks, Kn, B, pi=self._pi_at(S0))
         return C, T, E
 
     def z_init(self) -> np.ndarray:
@@ -718,28 +744,26 @@ class ParamOptProblem:
 
         Searches a small grid over the integer-ish actual variables and picks
         the smallest K0 restoring C <= C_max (C_m is non-increasing in K0).
-        The whole (grid x K0-ladder) search evaluates the closed-form
-        C/T/E surfaces as one broadcast NumPy computation; selection
-        semantics (ladder walk, first C-feasible rung, first-wins energy
-        ties) match the historical per-point loop.
+        For each cohort size (one, or a halving ladder where S is free)
+        the whole (grid x K0-ladder) search evaluates the closed-form
+        C/T/E surfaces as one broadcast NumPy computation, bitwise what
+        each grid point gives alone; selection semantics (ladder walk,
+        first C-feasible rung, first-wins energy ties) match the
+        historical per-point loop.
         """
         v = self.vmap
         names = v.names
         z = np.zeros(v.n)
-        gamma_grid = ([None] if self.m is not Objective.JOINT
-                      else [0.5 / self.consts.L, 0.1 / self.consts.L,
-                            0.01 / self.consts.L, 1.0 / self.consts.L])
-        B_grid = (1, 2, 4, 8, 16, 32, 64, 128)
-        K_grid = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32)
-        combos = [(gam, Bv, Kv) for gam in gamma_grid for Bv in B_grid
-                  for Kv in K_grid]
+        combos = self._init_combos()
         G = len(combos)
+        log_K = np.log([float(Kv) for _, _, Kv in combos])
+        log_B = np.log([float(Bv) for _, Bv, _ in combos])
         ZC = np.zeros((G, v.n))
         for i, nm in enumerate(names):
             if nm.startswith("K") and nm != "K0" or nm == "l":
-                ZC[:, i] = np.log([float(Kv) for _, _, Kv in combos])
+                ZC[:, i] = log_K
             elif nm == "B":
-                ZC[:, i] = np.log([float(Bv) for _, Bv, _ in combos])
+                ZC[:, i] = log_B
             elif nm == "extra" and self.m is Objective.JOINT:
                 ZC[:, i] = np.log([gam for gam, _, _ in combos])
         # paper variables at every grid point via the monomial map
@@ -749,21 +773,7 @@ class ParamOptProblem:
                    if self.m is Objective.JOINT else None)
         ks = self._k0_ladder()                                     # (L,)
         L = ks.shape[0]
-        # free-S problems search a halving ladder of cohort sizes too: the
-        # local GIA polishes within the basin this seed lands in, so the
-        # seed must compare S levels globally (the energy-optimal cohort
-        # can sit far below the cap)
-        if self._i_S is None:
-            S_levels = [None]
-        else:
-            cap = max(1.0, float(np.floor(
-                self.sampling.s_cap(self.sys.N) + 1e-9)))
-            S_levels, sv = [], cap
-            while True:
-                S_levels.append(sv)
-                if sv <= 1.0:
-                    break
-                sv = float(np.ceil(sv / 2.0))
+        S_levels = self._init_S_levels()
         best = None                    # (E, g, first_c rung, S level)
         for S0 in S_levels:
             C, T, E = self._grid_CTE(ks, Kn, B, gam_arr, S0)       # (G, L)

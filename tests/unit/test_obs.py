@@ -138,9 +138,9 @@ def test_prometheus_and_snapshot():
 # ---------------------------------------------------------------------------
 # tracer
 # ---------------------------------------------------------------------------
-def _profiled(tmp_path, fn):
+def _profiled_spans(tmp_path, fn):
     """Run ``fn`` under a profiler trace; -> the trace's ``repro.*`` host
-    events as (name, stats) in start order."""
+    events as (name, start ns, end ns, stats) in start order."""
     import glob
 
     import jax
@@ -153,11 +153,18 @@ def _profiled(tmp_path, fn):
         jax.profiler.stop_trace()
     path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
                           "*.xplane.pb"))
-    evs = [(ev.start_ns, ev.name, dict(ev.stats))
+    evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+            dict(ev.stats))
            for plane in ProfileData.from_file(path).planes
            for line in plane.lines for ev in line.events
            if ev.name.startswith("repro.")]
-    return [(n, st) for _, n, st in sorted(evs, key=lambda e: e[0])]
+    return sorted(evs, key=lambda e: e[1])
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a profiler trace; -> the trace's ``repro.*`` host
+    events as (name, stats) in start order."""
+    return [(n, st) for n, _, _, st in _profiled_spans(tmp_path, fn)]
 
 
 def test_tracer_noop_when_off(tmp_path):
@@ -192,6 +199,24 @@ def test_span_lands_in_profiler_trace(tmp_path, on):
                                          ("repro.inner", {})]
     assert [e["name"] for e in obs.TRACER.events()] == \
         (["inner", "outer"] if on else [])
+
+
+@pytest.mark.parametrize("on", [False, True])
+def test_z_init_span_nested_in_pack(tmp_path, on):
+    """A fused solve writes ``repro.gia.z_init`` (rows started cold, grid
+    points priced) inside ``repro.gia.pack``, obs on or off."""
+    if on:
+        obs.enable(reset=True)
+    scn = _scenario(system=SYS_1024, C_max=0.25)
+    spans = _profiled_spans(
+        tmp_path, lambda: scn.optimize(backend="jnp-fused"))
+    pack, = [s for s in spans if s[0] == "repro.gia.pack"]
+    z_init, = [s for s in spans if s[0] == "repro.gia.z_init"]
+    assert pack[1] <= z_init[1] <= z_init[2] <= pack[2]
+    assert z_init[3] == {"rows": 1,
+                         "grid_points": scn.problem().init_grid_points}
+    assert z_init[3]["grid_points"] == 80          # m = C: 8 B x 10 K_n
+    assert ("gia.z_init" in {e["name"] for e in obs.TRACER.events()}) is on
 
 
 def test_tracer_spans_and_chrome_export(tmp_path):
